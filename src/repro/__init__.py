@@ -1,9 +1,4 @@
 """repro: Distributed quasi-Newton robust estimation under differential
 privacy (Wang, Zhu & Zhu 2024) as a production JAX framework."""
-from repro import compat
-
-# Fill mesh-API gaps (AxisType, make_mesh axis_types, set_mesh, shard_map)
-# on older jax before any mesh-building code runs.
-compat.install()
 
 __version__ = "1.0.0"

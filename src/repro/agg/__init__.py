@@ -187,14 +187,20 @@ def aggregate(values, method: str = "dcq", scale=None, K: int = 10,
         p *= d
     be, params = _pick_backend(agg, backend, shape=(1, vals.shape[0], p))
     if be == "reference":
+        # both backends return the values' dtype (a bf16 model leaf must
+        # not come back f32 on one backend only)
         return agg.reference(values, scale=scale, K=K, trim_beta=trim_beta,
-                             axis=axis)
-    flat = vals.reshape(vals.shape[0], -1) if payload else vals[:, None]
+                             axis=axis).astype(values.dtype)
+    # the kernel sees (rows, m, cols): leading payload dims go to its batch
+    # grid. Flattening a 2-D payload into one coordinate axis instead makes
+    # the TPU compiler build a relayout whose compile time and memory grow
+    # with the leaf (minutes and GiBs for an embedding table).
+    cols = payload[-1] if payload else 1
+    kin = jnp.moveaxis(vals.reshape(vals.shape[0], -1, cols), 0, -2)
     sc = None
     if scale is not None:
-        sc = jnp.broadcast_to(scale, payload).reshape(-1) if payload \
-            else jnp.asarray(scale).reshape(1)
-    out = agg.pallas(flat, scale=sc, K=K, trim_beta=trim_beta,
+        sc = jnp.broadcast_to(jnp.asarray(scale), payload).reshape(-1, cols)
+    out = agg.pallas(kin, scale=sc, K=K, trim_beta=trim_beta,
                      interpret=interpret, **params)
     return out.reshape(payload).astype(values.dtype)
 
@@ -290,7 +296,7 @@ def aggregate_batched(values, method: str = "dcq", scale=None, K: int = 10,
                                   backend=backend, interpret=interpret)
         return jax.vmap(inner)(values)
     return agg.reference(values, scale=scale, K=K, trim_beta=trim_beta,
-                         axis=-2)
+                         axis=-2).astype(values.dtype)
 
 
 def median_mad_dcq(values, K: int = 10, backend: Optional[str] = None,

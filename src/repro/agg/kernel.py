@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.agg.reference import MAD_EPS, MAD_SIGMA
+from repro.agg.reference import MAD_EPS, MAD_SIGMA, normal_knots
 
 #: default bisection trip count — enough halvings to pin any fp32 value;
 #: the autotuner replaces this per bucket (32 already reaches fp32
@@ -84,11 +84,10 @@ def clamp_block(m: int, p: int, tile: int, inner: int,
 
 def cq_constants(K: int):
     """Host-side composite-quantile constants: the K standard-normal knots
-    ``Delta_k = Psi^{-1}(k/(K+1))`` and ``sum_k psi(Delta_k)`` — Python
-    floats baked into the kernel as compile-time scalars."""
-    from statistics import NormalDist
-    nd = NormalDist()
-    knots = tuple(nd.inv_cdf((k + 1.0) / (K + 1.0)) for k in range(K))
+    ``Delta_k = Psi^{-1}(k/(K+1))`` (the reference's own,
+    :func:`repro.agg.reference.normal_knots`) and ``sum_k psi(Delta_k)`` —
+    Python floats baked into the kernel as compile-time scalars."""
+    knots = normal_knots(K)
     psi_sum = sum(math.exp(-0.5 * d * d) for d in knots) \
         / math.sqrt(2.0 * math.pi)
     return knots, psi_sum
@@ -194,7 +193,7 @@ def _ostat_kernel(*refs, op: str, knots, psi_sum: float, g: int, kth: int,
             res = (_trimmed_cols(vals, g, n_bisect),)
         elif op == "dcq":
             med = _median_cols(vals, n_bisect)
-            scale = scale_ref[0, sl].astype(jnp.float32)  # (tile,)
+            scale = scale_ref[0, 0, sl].astype(jnp.float32)  # (tile,)
             res = (_cq_correct(vals, med, scale, knots, psi_sum),)
         elif op == "dcq_mad":
             med = _median_cols(vals, n_bisect)
@@ -210,7 +209,7 @@ def _ostat_kernel(*refs, op: str, knots, psi_sum: float, g: int, kth: int,
         else:
             raise ValueError(f"unknown order-statistics op {op!r}")
         for out_ref, r in zip(outs, res):
-            out_ref[0, sl] = r.astype(out_ref.dtype)
+            out_ref[0, 0, sl] = r.astype(out_ref.dtype)
 
 
 # --------------------------------------------------------- public entry
@@ -262,21 +261,25 @@ def ostat_pallas(values: jnp.ndarray, op: str, scale=None, *, K: int = 10,
         vals = jnp.pad(vals, ((0, 0), (0, 0), (0, pad)))
     pp = p + pad
 
+    # per-coordinate operands and outputs are (bn, 1, pp): the TPU lowering
+    # refuses a (1, block) block of a (bn, pp) array for bn > 1, since the
+    # second-minor block dim must be a multiple of 8 or the whole dim
     has_scale = op == "dcq"
     operands = [vals]
     in_specs = [pl.BlockSpec((1, m, block), lambda b, i: (b, 0, i))]
     if has_scale:
         if scale is None:
             raise ValueError("op='dcq' needs a per-coordinate scale")
-        sc = jnp.broadcast_to(scale, batch + (p,)).reshape((bn, p))
+        sc = jnp.broadcast_to(scale, batch + (p,)).reshape((bn, 1, p))
         if pad:
-            sc = jnp.pad(sc, ((0, 0), (0, pad)), constant_values=1.0)
+            sc = jnp.pad(sc, ((0, 0), (0, 0), (0, pad)),
+                         constant_values=1.0)
         operands.append(sc)
-        in_specs.append(pl.BlockSpec((1, block), lambda b, i: (b, i)))
+        in_specs.append(pl.BlockSpec((1, 1, block), lambda b, i: (b, 0, i)))
 
     n_out = 3 if op == "median_mad_dcq" else 1
-    out_spec = pl.BlockSpec((1, block), lambda b, i: (b, i))
-    out_shape = [jax.ShapeDtypeStruct((bn, pp), values.dtype)
+    out_spec = pl.BlockSpec((1, 1, block), lambda b, i: (b, 0, i))
+    out_shape = [jax.ShapeDtypeStruct((bn, 1, pp), values.dtype)
                  for _ in range(n_out)]
     outs = pl.pallas_call(
         functools.partial(_ostat_kernel, op=op, knots=knots,
@@ -289,7 +292,7 @@ def ostat_pallas(values: jnp.ndarray, op: str, scale=None, *, K: int = 10,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
-    outs = tuple(o[:, :p].reshape(batch + (p,)) for o in outs)
+    outs = tuple(o[:, 0, :p].reshape(batch + (p,)) for o in outs)
     return outs if n_out > 1 else outs[0]
 
 

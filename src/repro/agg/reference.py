@@ -33,10 +33,10 @@ We implement the centred form (see DESIGN.md §1).
 from __future__ import annotations
 
 import functools
+from statistics import NormalDist
 
 import jax
 import jax.numpy as jnp
-from jax.scipy.special import ndtri  # Psi^{-1}
 from jax.scipy.stats import norm
 
 #: MAD -> sd consistency factor for the normal reference distribution.
@@ -53,9 +53,20 @@ def quantile_levels(K: int) -> jnp.ndarray:
                       else jnp.float32) / (K + 1)
 
 
+@functools.lru_cache(maxsize=None)
+def normal_knots(K: int) -> tuple:
+    """Delta_k = Psi^{-1}(k/(K+1)), k = 1..K, as Python floats computed on
+    the host in double precision. The reference and the Pallas kernel both
+    take their knots from here, so they compare values against the same
+    thresholds (a device's single-precision ``ndtri`` can be off by
+    hundreds of ulp in the tails, which moves whole indicators)."""
+    nd = NormalDist()
+    return tuple(nd.inv_cdf((k + 1.0) / (K + 1.0)) for k in range(K))
+
+
 def quantile_knots(K: int) -> jnp.ndarray:
     """Delta_k = Psi^{-1}(kappa_k) for the standard-normal reference G."""
-    return ndtri(quantile_levels(K))
+    return jnp.asarray(normal_knots(K), dtype=quantile_levels(K).dtype)
 
 
 def d_k(K: int) -> float:

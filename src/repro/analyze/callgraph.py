@@ -19,8 +19,7 @@ from pathlib import Path
 # so a Call argument marks the factory itself (its nested defs are then
 # reached through ordinary reference edges).
 JIT_WRAPPERS = ("jax.jit", "jit")
-SHARD_WRAPPERS = ("jax.experimental.shard_map.shard_map", "shard_map",
-                  "repro.compat.shard_map", "machine_map",
+SHARD_WRAPPERS = ("jax.shard_map", "shard_map", "machine_map",
                   "repro.dist.sharded_protocol.machine_map")
 PALLAS_WRAPPERS = ("jax.experimental.pallas.pallas_call", "pl.pallas_call",
                    "pallas_call")

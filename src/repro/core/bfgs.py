@@ -25,7 +25,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.transport import tree_dot, tree_scale
+from repro.core.transport import tree_dot
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,19 +168,24 @@ def lbfgs_two_loop_tree(mem: LBFGSMemory, g: Any, gamma=1.0) -> Any:
         rho = jnp.where(ok, 1.0 / jnp.maximum(tree_dot(s, y), 1e-12), 0.0)
         a = rho * tree_dot(s, q)
         coef = jnp.where(ok, a, 0.0)
-        q = jax.tree_util.tree_map(lambda qq, yy: qq - coef * yy, q, y)
+        # curvature scalars (and gamma) are f32; each leaf keeps its own
+        # dtype, so a mixed bf16/f32 parameter tree is a valid scan carry
+        # and the step returns parameters of the dtypes it was given
+        q = jax.tree_util.tree_map(
+            lambda qq, yy: (qq - coef * yy).astype(qq.dtype), q, y)
         return q, a
 
     q, alphas = jax.lax.scan(bwd, g, (mem.s_hist, mem.y_hist, valid),
                              reverse=True)
-    r = tree_scale(gamma, q)
+    r = jax.tree_util.tree_map(lambda x: (gamma * x).astype(x.dtype), q)
 
     def fwd(r, inp):
         s, y, ok, a = inp
         rho = jnp.where(ok, 1.0 / jnp.maximum(tree_dot(s, y), 1e-12), 0.0)
         b = rho * tree_dot(y, r)
         coef = jnp.where(ok, a - b, 0.0)
-        r = jax.tree_util.tree_map(lambda rr, ss: rr + coef * ss, r, s)
+        r = jax.tree_util.tree_map(
+            lambda rr, ss: (rr + coef * ss).astype(rr.dtype), r, s)
         return r, None
 
     r, _ = jax.lax.scan(fwd, r, (mem.s_hist, mem.y_hist, valid, alphas))

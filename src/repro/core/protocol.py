@@ -70,6 +70,19 @@ def vmap_machines(fn, *machine_args, bcast=()):
     return jax.vmap(lambda *ma: fn(*ma, *bcast))(*machine_args)
 
 
+def at_center(fn, *args):
+    """Default center placement: the center's aggregation ``fn(*args)``
+    runs where it is traced. dist/sharded_protocol.py provides the form
+    for a machine mesh (``center_map``)."""
+    return fn(*args)
+
+
+def center_aggregate(center, values, method: str, scale=None, **kw):
+    """``wire_aggregate`` of one transmission, placed by ``center``."""
+    return center(lambda v, s: wire_aggregate(v, method, scale=s, **kw),
+                  values, scale)
+
+
 def monte_carlo_mrse(thetas: jnp.ndarray, target: jnp.ndarray) -> float:
     """Mean root-square error over the replicate axis of a
     ``run_monte_carlo`` output field: thetas (reps, p), target (p,)."""
@@ -240,7 +253,7 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
                     theta_cq_override: Optional[jnp.ndarray] = None,
                     machine_map=vmap_machines,
                     eps=None, delta=None,
-                    sigma_base=None) -> ProtocolArrays:
+                    sigma_base=None, center=at_center) -> ProtocolArrays:
     """Paper Algorithm 1 as a pure function: arrays in, arrays out.
 
     jit-compatible with ``problem``/``cfg``/``attack``/``machine_map``
@@ -254,7 +267,8 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
     ``calibrate_sigma_base`` — the sweep executor computes them host-side
     in float64 per scenario and vmaps over them, so scenarios differing
     only in privacy budget share one compiled executable and reproduce the
-    static path bit-for-bit.
+    static path bit-for-bit. ``center`` places the center's aggregations
+    (``at_center``, or ``dist.sharded_protocol.center_map`` on a mesh).
     """
     prob = problem
     m_plus_1, n, p = X.shape
@@ -313,12 +327,12 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         lam_j = jnp.full((m_plus_1,), cfg.lambda_s, X.dtype)
     s1_base = sb["R1 theta"]
     s1_j = s1_base / lam_j                         # per-machine sd
-    s1 = wire_aggregate(s1_j, "median")            # reported/summary value
+    s1 = center_aggregate(center, s1_j, "median")  # reported/summary value
     theta_dp = noise(keys[0], theta_local, s1_j)   # per-machine (m+1,) sd
     theta_dp = corrupt(theta_dp, keys[1], 0)
     sig.append(s1)
 
-    theta_med = wire_aggregate(theta_dp, "median")
+    theta_med = center_aggregate(center, theta_dp, "median")
     if cfg.center_trust == "trusted":
         sig2 = local.sandwich_diag_variance(prob, theta_med, Xc, yc)
     else:
@@ -327,8 +341,8 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
     s1_eff = 0.0 if cfg.noiseless else s1_j[0]     # center's estimate
     scale1 = jnp.sqrt((sig2 + n * s1_eff ** 2)) / jnp.sqrt(n)
     agg1 = "median" if cfg.center_trust == "untrusted" else cfg.aggregator
-    theta_cq = wire_aggregate(theta_dp, agg1, scale=scale1, K=cfg.K,
-                              trim_beta=cfg.trim_beta)
+    theta_cq = center_aggregate(center, theta_dp, agg1, scale=scale1,
+                                K=cfg.K, trim_beta=cfg.trim_beta)
     if theta_cq_override is not None:
         # warm start / ablation hook: continue the protocol from a
         # caller-supplied initial estimate.
@@ -356,10 +370,10 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         node_gvar = wire_corrupt(keys[5], node_gvar, byz_mask[1:],
                                  attack=attack, factor=attack_factor,
                                  round_idx=1)
-        gvar = wire_aggregate(node_gvar, "median")
+        gvar = center_aggregate(center, node_gvar, "median")
         sig.append(s6)
     scale2 = jnp.sqrt(jnp.maximum(gvar, 1e-12) + n * s2_eff ** 2) / jnp.sqrt(n)
-    g_cq = _agg_for(cfg, "grad", grads_dp, scale2)
+    g_cq = _agg_for(cfg, "grad", grads_dp, scale2, center)
 
     # ---- Round 3: Newton directions -> theta_os -----------------------
     def newton_dir(Xi, yi, t_cq, g):
@@ -379,7 +393,7 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         hvar = median_deviation_variance(dirs_dp, n)
     s3_0 = (s3 / lam_j[0]) * jnp.linalg.norm(dirs[0])
     scale3 = jnp.sqrt(jnp.maximum(hvar, 1e-12) + n * s3_0 ** 2) / jnp.sqrt(n)
-    H1 = _agg_for(cfg, "dir", dirs_dp, scale3)
+    H1 = _agg_for(cfg, "dir", dirs_dp, scale3, center)
     theta_os = theta_cq - H1
 
     # ---- Round 4: gradient differences -> gdiff_cq, g_os --------------
@@ -403,10 +417,11 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         gosvar = gvar
     scale4 = jnp.sqrt(jnp.maximum(gdvar, 1e-12)
                       + n * s4_eff ** 2) / jnp.sqrt(n)
-    gdiff_cq = _agg_for(cfg, "gdiff", gdiff_dp, scale4)
+    gdiff_cq = _agg_for(cfg, "gdiff", gdiff_dp, scale4, center)
     scale4b = jnp.sqrt(jnp.maximum(gosvar, 1e-12) + n * s2_eff ** 2
                        + n * s4_eff ** 2) / jnp.sqrt(n)
-    g_os = _agg_for(cfg, "g_os", grads_dp + gdiff_dp, scale4b)
+    g_os = _agg_for(cfg, "g_os", grads_dp + gdiff_dp, scale4b,
+                    center)
 
     # ---- Round 5: BFGS directions -> theta_qn --------------------------
     v = make_v(s=step, y=gdiff_cq)
@@ -430,7 +445,7 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         h3var = median_deviation_variance(h3_dp, n)
     s5_0 = s5 * jnp.linalg.norm(h3[0])
     scale5 = jnp.sqrt(jnp.maximum(h3var, 1e-12) + n * s5_0 ** 2) / jnp.sqrt(n)
-    h3_agg = _agg_for(cfg, "h3", h3_dp, scale5)
+    h3_agg = _agg_for(cfg, "h3", h3_dp, scale5, center)
     # center-side rank-1 term: rho (s s^T) g_os  (below eq. 4.15)
     H2 = h3_agg + v.rho * step * jnp.dot(step, g_os)
     theta_qn = theta_os - H2
@@ -446,7 +461,7 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         v_s=v.s, v_y=v.y, v_rho=v.rho)
 
 
-def _agg_for(cfg: ProtocolConfig, name: str, values, scale):
+def _agg_for(cfg: ProtocolConfig, name: str, values, scale, center):
     """Untrusted-center mode uses the median everywhere except the gradient
     round (paper §4.3 keeps DCQ for 'crucial statistics such as gradients').
 
@@ -454,9 +469,9 @@ def _agg_for(cfg: ProtocolConfig, name: str, values, scale):
     registry verbatim (byte parity), pytrees dispatch per leaf.
     """
     if cfg.center_trust == "untrusted" and name not in ("grad",):
-        return wire_aggregate(values, method="median")
-    return wire_aggregate(values, method=cfg.aggregator, scale=scale,
-                          K=cfg.K, trim_beta=cfg.trim_beta)
+        return center_aggregate(center, values, "median")
+    return center_aggregate(center, values, cfg.aggregator, scale=scale,
+                            K=cfg.K, trim_beta=cfg.trim_beta)
 
 
 # ---------------------------------------------- pytree (model-scale) engine
@@ -481,7 +496,8 @@ def protocol_tree_rounds(key: jax.Array, theta, batches, grad_fn,
                          byz_mask: Optional[jnp.ndarray] = None,
                          attack: str = "none", attack_factor=-3.0,
                          sigmas=None, n: Optional[int] = None,
-                         machine_map=vmap_machines) -> ProtocolTreeArrays:
+                         machine_map=vmap_machines,
+                         center=at_center) -> ProtocolTreeArrays:
     """Algorithm 1's five transmissions over an arbitrary parameter pytree
     — one robust DP quasi-Newton training step for the model zoo.
 
@@ -507,8 +523,8 @@ def protocol_tree_rounds(key: jax.Array, theta, batches, grad_fn,
     (s, y) pairs.
 
     Pure and compile-once like ``protocol_rounds``: jit with ``grad_fn``,
-    ``cfg``, ``attack``, ``machine_map`` static; vmap over ``key`` for
-    replicates. ``batches``: pytree with leading machine axis m;
+    ``cfg``, ``attack``, ``machine_map``, ``center`` static; vmap over
+    ``key`` for replicates. ``batches``: pytree with leading machine axis m;
     ``grad_fn(theta, batch) -> (loss, grad_tree)``. ``sigmas`` overrides
     the per-leaf calibration ({transmission: sigma pytree},
     dp.calibrate_tree_sigmas); otherwise it is computed here from
@@ -538,8 +554,8 @@ def protocol_tree_rounds(key: jax.Array, theta, batches, grad_fn,
         vals = wire_noise(k_noise, values, sigmas[name], noiseless=noiseless)
         vals = wire_corrupt(k_corrupt, vals, byz_mask, attack=attack,
                             factor=attack_factor, round_idx=rnd)
-        return wire_aggregate(vals, method=cfg.aggregator, K=cfg.K,
-                              trim_beta=cfg.trim_beta)
+        return center_aggregate(center, vals, cfg.aggregator, K=cfg.K,
+                                trim_beta=cfg.trim_beta)
 
     # ---- R1: machine-local steps -> theta_cq --------------------------
     def local_fit(batch):
@@ -609,12 +625,14 @@ class DPQNProtocol:
     """
 
     def __init__(self, problem: MEstimationProblem, cfg: ProtocolConfig,
-                 machine_map=None, jit: bool = True):
+                 machine_map=None, jit: bool = True, center=None):
         self.problem = problem
         self.cfg = cfg
-        # machine_map(fn, *machine_args, bcast=()) runs fn once per machine;
-        # the SPMD protocol passes a shard_map-based implementation.
+        # machine_map(fn, *machine_args, bcast=()) runs fn once per machine
+        # and center(fn, *args) runs the center's aggregations; the SPMD
+        # protocol passes shard_map-based implementations of both.
         self._mmap = machine_map or vmap_machines
+        self._center = center or at_center
         self._jit = jit
         self.trace_count = 0
         self._engines = {}   # attack -> (single, batched)
@@ -630,7 +648,7 @@ class DPQNProtocol:
                     key, X, y, self.problem, self.cfg, byz_mask=byz_mask,
                     attack=attack, attack_factor=attack_factor,
                     theta0=theta0, theta_cq_override=theta_cq_override,
-                    machine_map=self._mmap)
+                    machine_map=self._mmap, center=self._center)
             batched = jax.vmap(rounds, in_axes=(0,) + (None,) * 6)
             if self._jit:
                 rounds, batched = jax.jit(rounds), jax.jit(batched)

@@ -10,10 +10,9 @@ for both regimes:
 
   * every primitive takes ``values`` as EITHER a single ``(m, ...)`` array
     OR a pytree of them, and dispatches per leaf;
-  * each leaf is flattened to ``(m, d_leaf)`` at the aggregation boundary
-    and unflattened afterwards — the registry kernels (repro.agg) only
-    ever see 2-D machine-by-coordinate tiles, so the batched Pallas
-    order-statistics path applies unchanged to every leaf of a model;
+  * each leaf ``(m, *payload)`` is aggregated whole; repro.agg lays it
+    out as ``(rows, m, cols)`` for the batched Pallas order-statistics
+    kernel, so that path applies unchanged to every leaf of a model;
   * noise scales (``sigma``) and aggregation scales may be scalars,
     per-machine ``(m,)`` vectors, or pytrees matching ``values`` — the
     per-leaf DP calibration (core/dp.py) feeds pytree sigmas so each
@@ -173,11 +172,11 @@ def wire_aggregate(values: Any, method: str, scale: Any = None,
     """Robust aggregation of the leading machine axis, per leaf, through
     the ``repro.agg`` registry.
 
-    Flatten/unflatten boundary: every pytree leaf ``(m, *payload)`` is
-    reshaped to ``(m, d_leaf)`` before dispatch — the registry's batched
-    kernels only ever see 2-D tiles — and the aggregate is reshaped back
-    to ``payload``. Single arrays pass through at their native shape
-    (bit-identical to the historical flat path).
+    Every pytree leaf ``(m, *payload)`` goes to the registry whole and
+    comes back as ``payload`` (per coordinate, the same numbers as the
+    historical flattened ``(m, d_leaf)`` call). Single arrays pass
+    through at their native shape (bit-identical to the historical flat
+    path).
 
     ``fill`` (the serving path): when given, the leading axis is a
     fixed-capacity ring buffer whose first ``fill`` (traced scalar) rows
@@ -208,14 +207,11 @@ def wire_aggregate(values: Any, method: str, scale: Any = None,
     scales = _match(values, scale)
     out = []
     for leaf, sc in zip(leaves, scales):
-        payload = leaf.shape[1:]
-        flat = leaf.reshape(leaf.shape[0], -1)
-        fsc = None
+        # leaves go whole: repro.agg lays a 2-D payload out for the kernel
+        # (flattening it here costs the TPU compiler minutes per leaf)
         if sc is not None:
-            fsc = jnp.broadcast_to(jnp.asarray(sc, leaf.dtype),
-                                   payload).reshape(-1) if payload \
-                else jnp.asarray(sc, leaf.dtype).reshape(1)
-        red = agg.aggregate(flat, method=method, scale=fsc, K=K,
+            sc = jnp.broadcast_to(jnp.asarray(sc, leaf.dtype), leaf.shape[1:])
+        red = agg.aggregate(leaf, method=method, scale=sc, K=K,
                             trim_beta=trim_beta, axis=0, backend=backend)
-        out.append(red.reshape(payload).astype(leaf.dtype))
+        out.append(red.astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(treedef, out)

@@ -25,7 +25,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.agg import get_aggregator
-from repro.compat import shard_map
 from repro.dist.grad_agg import GradAggConfig, aggregate_machine_axis
 
 
@@ -95,5 +94,5 @@ def sharded_aggregate_leaf(values: jax.Array, cfg: GradAggConfig,
         full = jax.lax.all_gather(x, machine_axis, axis=0, tiled=True)
         return aggregate_machine_axis(full, cfg)
 
-    return shard_map(gather_and_reduce, mesh=mesh, in_specs=(spec,),
-                     out_specs=rest, check_rep=False)(values)
+    return jax.shard_map(gather_and_reduce, mesh=mesh, in_specs=(spec,),
+                         out_specs=rest, check_vma=False)(values)

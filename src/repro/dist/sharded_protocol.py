@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ProtocolConfig, TreeProtocolConfig
 from repro.core.losses import MEstimationProblem
 from repro.core.protocol import (DPQNProtocol, ProtocolResult,
@@ -56,10 +55,24 @@ def machine_map(mesh: Mesh, axis: str = "machines"):
             return jax.vmap(lambda *ma: fn(*ma, *bc))(*local_args)
 
         in_specs = (P(axis),) * n_machine + (P(),) * len(bcast)
-        return shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(axis), check_rep=False)(
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(axis), check_vma=False)(
                              *machine_args, *bcast)
     return mmap
+
+
+def center_map(mesh: Mesh):
+    """Build the center placement for ``mesh`` (core.protocol.at_center's
+    mesh form). The center receives every machine's transmission and
+    aggregates it; here each device plays the center on the gathered,
+    replicated rows, so the result is replicated too. It is also what lets
+    the Pallas order-statistics kernel run on a mesh: a Mosaic kernel
+    cannot be partitioned automatically, but inside ``shard_map`` each
+    device runs it whole."""
+    def center(fn, *args):
+        return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(*args)
+    return center
 
 
 def run_sharded(prob: MEstimationProblem, cfg: ProtocolConfig, mesh: Mesh,
@@ -84,7 +97,7 @@ def run_sharded(prob: MEstimationProblem, cfg: ProtocolConfig, mesh: Mesh,
     X = jax.device_put(X, machine_sharding)
     y = jax.device_put(y, machine_sharding)
     proto = DPQNProtocol(prob, cfg, machine_map=machine_map(mesh, axis),
-                         jit=jit)
+                         jit=jit, center=center_map(mesh))
     res: ProtocolResult = proto.run(key, X, y, byz_mask=byz_mask,
                                     attack=attack,
                                     attack_factor=attack_factor,
@@ -127,7 +140,7 @@ def run_sharded_tree(key: jax.Array, theta, batches, grad_fn,
         return protocol_tree_rounds(
             key, theta, batches, grad_fn, cfg, mem=mem, byz_mask=byz_mask,
             attack=attack, attack_factor=attack_factor, n=n,
-            machine_map=mmap)
+            machine_map=mmap, center=center_map(mesh))
     if jit:
         run = jax.jit(run)
     return run(key, theta, batches, mem, byz_mask)

@@ -8,29 +8,31 @@ layout; the multi-pod config stacks a leading "pod" axis (2, 16, 16).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
     """Whatever devices exist locally, data-major (CPU tests/examples)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
-def make_machine_mesh(m: int) -> Mesh:
-    """1-D mesh for the SPMD protocol (one device per machine)."""
-    return make_mesh((m,), ("machines",), axis_types=(AxisType.Auto,))
+def make_machine_mesh(n_devices: int) -> Mesh:
+    """1-D ``("machines",)`` mesh over ``n_devices`` devices for the SPMD
+    protocol. The axis is ``Auto``: jax.make_mesh defaults to ``Explicit``
+    axes, under which slicing a machine-sharded array (Algorithm 1 takes
+    machine 0 as the center) is a sharding type error."""
+    return jax.make_mesh((n_devices,), ("machines",),
+                         axis_types=(AxisType.Auto,))
 
 
 # roofline hardware constants (TPU v5e, per chip)

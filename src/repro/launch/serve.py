@@ -32,7 +32,8 @@ from repro.attacks import registered as registered_attacks
 from repro.configs import get_config
 from repro.core.keys import stream_key
 from repro.core.transport import wire_corrupt
-from repro.launch.cli import add_common_flags, machine_mesh
+from repro.launch.cli import (add_common_flags, enable_compile_cache,
+                              machine_mesh)
 from repro.models.model import Model
 from repro.serve import AggregationService, FlushPolicy, ServeConfig
 
@@ -147,4 +148,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -33,7 +33,8 @@ from repro.core.keys import stream_key
 from repro.configs.base import TreeProtocolConfig
 from repro.data.lm import synthetic_lm_batches
 from repro.dist.grad_agg import GradAggConfig
-from repro.launch.cli import add_common_flags, machine_mesh
+from repro.launch.cli import (add_common_flags, enable_compile_cache,
+                              machine_mesh)
 from repro.models.model import Model
 from repro.train.optimizer import AdamW
 from repro.train.trainer import (QNTrainConfig, QNTrainer, TrainConfig,
@@ -77,6 +78,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    """Run the launcher; returns ``(trainer, losses)``."""
     args = build_parser().parse_args(argv)
 
     cfg = get_config(args.arch, reduced=args.reduced)
@@ -140,8 +142,9 @@ def main(argv=None):
                         meta={"arch": args.arch, "agg": args.agg,
                               "optimizer": args.optimizer})
         print(f"[train] checkpoint -> {args.ckpt}")
-    return losses
+    return trainer, losses
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

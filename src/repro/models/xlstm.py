@@ -28,6 +28,12 @@ from repro.models.layers import _init
 Params = Dict[str, jnp.ndarray]
 
 NEG_INF = -1e30
+#: floor on the stabiliser m inside the normaliser max(|den|, exp(-m)):
+#: exp(-m) overflows f32 below m = -88.7, and although the forward pass
+#: then still gives h = num / inf = 0, the backward pass multiplies that
+#: zero by exp(-m) = inf and every gradient upstream turns NaN (a strongly
+#: negative input gate does it). Below the floor h < num * e^-80 ~ 0.
+M_FLOOR = -80.0
 
 
 # ================================================================== mLSTM
@@ -125,7 +131,8 @@ def mlstm_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         m0 = jnp.full((B, Q, H), NEG_INF, jnp.float32)
         (num, den, m), _ = jax.lax.scan(kv_step, (num0, den0, m0),
                                         (kc, vc, bc, pos))
-        h = num / jnp.maximum(jnp.abs(den), jnp.exp(-m))[..., None]
+        h = num / jnp.maximum(jnp.abs(den),
+                              jnp.exp(-jnp.maximum(m, M_FLOOR)))[..., None]
         return None, h
 
     _, h = jax.lax.scan(q_step, None, (qc, ac, pos))
@@ -169,7 +176,7 @@ def mlstm_decode(p: Params, x: jnp.ndarray, cache: Params,
     qf = q.astype(jnp.float32) * scale
     num = jnp.einsum("bhd,bhde->bhe", qf, C)
     den = jnp.maximum(jnp.abs(jnp.einsum("bhd,bhd->bh", qf, n)),
-                      jnp.exp(-m_new))
+                      jnp.exp(-jnp.maximum(m_new, M_FLOOR)))
     h = (num / den[..., None]).reshape(B, 1, d_inner)
     out = h.astype(x.dtype) * gate
     return (jnp.einsum("bse,ed->bsd", out, p["w_down"]),

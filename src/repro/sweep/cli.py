@@ -95,9 +95,9 @@ def main(argv=None) -> int:
     mesh = None
     if args.sharded:
         import jax
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_machine_mesh
         n_dev = jax.device_count()
-        mesh = make_mesh((n_dev,), ("machines",))
+        mesh = make_machine_mesh(n_dev)
         print(f"sharding machine axis over {n_dev} device(s)")
 
     out = args.out or _default_out(args.preset)
@@ -128,4 +128,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cli import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

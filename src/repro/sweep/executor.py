@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import n_transmissions, protocol_rounds, vmap_machines
-from repro.core.protocol import calibrate_sigma_base
+from repro.core.protocol import at_center, calibrate_sigma_base
 from repro.sweep import artifact as artifact_mod
 from repro.sweep.comm import comm_record
 from repro.sweep.data import (build_data, byz_mask, compute_metrics,
@@ -60,10 +60,11 @@ class SweepExecutor:
                  chunk_size: Optional[int] = None):
         self.mesh = mesh
         if mesh is None:
-            self._mmap = vmap_machines
+            self._mmap, self._center = vmap_machines, at_center
         else:
-            from repro.dist.sharded_protocol import machine_map
+            from repro.dist.sharded_protocol import center_map, machine_map
             self._mmap = machine_map(mesh, mesh.axis_names[0])
+            self._center = center_map(mesh)
         self.progress = progress or (lambda msg: None)
         if chunk_size is not None and chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
@@ -81,7 +82,7 @@ class SweepExecutor:
         cfg = scenario.protocol_config()
         problem = _problem_for(scenario)
         attack = scenario.attack
-        mmap = self._mmap
+        mmap, center = self._mmap, self._center
         self.trace_counts[gkey] = 0
 
         def one_rep(key, X, y, mask, eps, delta, factor, sigma_base):
@@ -89,7 +90,7 @@ class SweepExecutor:
             return protocol_rounds(
                 key, X, y, problem, cfg, byz_mask=mask, attack=attack,
                 attack_factor=factor, eps=eps, delta=delta,
-                sigma_base=sigma_base, machine_map=mmap)
+                sigma_base=sigma_base, machine_map=mmap, center=center)
 
         over_reps = jax.vmap(one_rep, in_axes=(0,) + (None,) * 7)
         over_scenarios = jax.vmap(over_reps, in_axes=(0,) * 8)
@@ -113,7 +114,7 @@ class SweepExecutor:
         model = Model(cfg, remat=True)
         tcfg = scenario.protocol_config()
         attack = scenario.attack
-        mmap = self._mmap
+        mmap, center = self._mmap, self._center
         self.trace_counts[gkey] = 0
 
         def grad_fn(params, mb):
@@ -126,7 +127,7 @@ class SweepExecutor:
             return protocol_tree_rounds(
                 key, params, mb, grad_fn, tcfg, mem=mem, byz_mask=mask,
                 attack=attack, attack_factor=factor, sigmas=sigmas,
-                machine_map=mmap)
+                machine_map=mmap, center=center)
 
         engine = (jax.jit(step), model, cfg)
         self._engines[gkey] = engine

@@ -22,10 +22,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
 from repro.configs.base import TreeProtocolConfig
-from repro.core.protocol import protocol_tree_rounds
+from repro.core.protocol import at_center, protocol_tree_rounds
 from repro.dist.collectives import tree_machine_specs
 from repro.dist.grad_agg import (GradAggConfig, robust_aggregate,
                                  spend_record)
+from repro.dist.sharded_protocol import center_map
 from repro.models.model import Model
 from repro.train.optimizer import AdamW, apply_updates, global_norm
 
@@ -144,6 +145,9 @@ def make_qn_train_step(model: Model, qcfg: QNTrainConfig,
     """
     loss_fn = make_loss_fn(model, qcfg.remat)
     m = qcfg.n_machines
+    # on a mesh every device plays the center (the aggregation kernels
+    # cannot be partitioned automatically)
+    center = at_center if mesh is None else center_map(mesh)
 
     def grad_fn(params, mb):
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -164,7 +168,7 @@ def make_qn_train_step(model: Model, qcfg: QNTrainConfig,
         out = protocol_tree_rounds(
             key, params, mb, grad_fn, qcfg.protocol, mem=mem,
             byz_mask=byz_mask, attack=qcfg.attack,
-            attack_factor=qcfg.attack_factor, n=n)
+            attack_factor=qcfg.attack_factor, n=n, center=center)
         metrics = {"loss": out.losses.mean(),
                    "loss_per_machine": out.losses,
                    "grad_norm": out.grad_norm}
@@ -176,12 +180,19 @@ def make_qn_train_step(model: Model, qcfg: QNTrainConfig,
 class QNTrainer:
     """Protocol-driven loop: the model zoo trained by the SAME engine as
     the p=10 convex head — five DP transmissions, registry attacks and
-    aggregators, per-leaf calibrated noise, L-BFGS curvature memory."""
+    aggregators, per-leaf calibrated noise, L-BFGS curvature memory.
+    ``trace_count`` counts traces of the step: one per compile."""
 
     def __init__(self, model: Model, qcfg: QNTrainConfig,
                  mesh: Optional[Mesh] = None):
         self.model, self.qcfg = model, qcfg
-        self.step_fn = jax.jit(make_qn_train_step(model, qcfg, mesh))
+        self.trace_count = 0
+        step = make_qn_train_step(model, qcfg, mesh)
+
+        def counted(*args):
+            self.trace_count += 1
+            return step(*args)
+        self.step_fn = jax.jit(counted)
 
     def init_memory(self, params):
         from repro.core.bfgs import LBFGSMemory

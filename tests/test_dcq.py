@@ -14,6 +14,20 @@ def test_quantile_levels():
     np.testing.assert_allclose(np.asarray(k), np.arange(1, 11) / 11, rtol=1e-6)
 
 
+def test_quantile_knots_are_host_constants_shared_with_kernel():
+    """Reference and kernel compare against the same knots: correctly
+    rounded normal quantiles, not a device's single-precision ndtri (off
+    by ~170 ulp in the tails on a TPU, which flips CQ indicators)."""
+    from statistics import NormalDist
+
+    from repro.agg.kernel import cq_constants
+    from repro.core.dcq import quantile_knots
+    want = np.float32([NormalDist().inv_cdf(k / 11) for k in range(1, 11)])
+    got = np.asarray(quantile_knots(10))
+    assert got.dtype == np.float32 and (got == want).all()
+    assert (np.float32(cq_constants(10)[0]) == got).all()
+
+
 def test_dk_limit_is_pi_over_3():
     # K -> inf: D_K -> pi/3 (ARE -> 3/pi ~ 0.955). Paper §1.2(2).
     assert abs(d_k(200) - np.pi / 3) < 0.01

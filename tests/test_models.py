@@ -66,6 +66,35 @@ def test_mlstm_chunked_equals_recurrence():
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4)
 
 
+@pytest.mark.parametrize("shift", [0.0, -120.0])
+def test_mlstm_gradient_finite_with_negative_input_gate(shift):
+    """An input-gate pre-activation below -88.7 made exp(-m) overflow in
+    the normaliser: the loss stayed finite but every gradient upstream was
+    NaN (first seen in full-width xlstm-125m QN training). The output is
+    unchanged and the gradient finite."""
+    cfg = get_config("xlstm-125m", reduced=True)
+    pm = xlstm.mlstm_init(jax.random.PRNGKey(5), cfg)
+    H = cfg.n_heads
+    pm["b_if"] = pm["b_if"].at[:H].add(shift)
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(6), (2, 50, cfg.d_model))
+
+    def f(p):
+        return jnp.sum(xlstm.mlstm_forward(p, x, cfg, chunk=16) ** 2)
+    val, grads = jax.value_and_grad(f)(pm)
+    assert np.isfinite(float(val))
+    for g in jax.tree_util.tree_leaves(grads):
+        assert np.isfinite(np.asarray(g)).all()
+    if shift:
+        # the input gate shuts: output (and loss) near zero, not NaN
+        assert float(val) < 1e-20
+        cache = xlstm.mlstm_cache_init(cfg, 2)
+        o, _ = xlstm.mlstm_decode(pm, x[:, :1], cache, cfg)
+        g = jax.grad(lambda p: jnp.sum(xlstm.mlstm_decode(
+            p, x[:, :1], cache, cfg)[0] ** 2))(pm)
+        assert all(np.isfinite(np.asarray(v)).all()
+                   for v in jax.tree_util.tree_leaves(g))
+
+
 # ------------------------------------------- prefill/decode consistency
 
 @pytest.mark.parametrize("arch", ["glm4-9b", "zamba2-7b", "xlstm-125m",

@@ -6,6 +6,7 @@ rewritten robust-training example."""
 import importlib.util
 import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,40 @@ def test_lbfgs_two_loop_tree_matches_flat():
         np.concatenate([d2["a"], d2["b"]]),
         np.asarray(bfgs.lbfgs_two_loop(mem_flat2, g, gamma=0.7)),
         rtol=1e-5, atol=1e-6)
+
+
+def test_lbfgs_two_loop_tree_keeps_mixed_leaf_dtypes():
+    """Full-width models mix bf16 and f32 leaves; the f32 curvature
+    scalars must not promote a bf16 leaf, or the scan carry changes type
+    and the QN step does not trace."""
+    key = jax.random.PRNGKey(5)
+    dtypes = {"w": jnp.bfloat16, "b": jnp.float32}
+
+    def draw(i):
+        return {k: jax.random.normal(jax.random.fold_in(key, 2 * i + j),
+                                     (4,)).astype(dt)
+                for j, (k, dt) in enumerate(dtypes.items())}
+    mem = bfgs.LBFGSMemory.init_like(
+        3, {k: jnp.zeros(4, dt) for k, dt in dtypes.items()})
+    mem32 = bfgs.LBFGSMemory.init_like(3, {k: jnp.zeros(4) for k in dtypes})
+    for i in range(2):
+        s = draw(i)
+        y = jax.tree_util.tree_map(lambda v: (1.5 * v).astype(v.dtype), s)
+        mem = mem.push(s, y)
+        mem32 = mem32.push(*(jax.tree_util.tree_map(
+            lambda v: v.astype(jnp.float32), t) for t in (s, y)))
+    g = draw(7)
+
+    def two_loop(mm, gg):       # the f32 Barzilai-Borwein gamma, as in R3
+        return bfgs.lbfgs_two_loop_tree(mm, gg, gamma=bfgs.lbfgs_gamma(mm))
+    d = jax.jit(two_loop)(mem, g)
+    assert {k: v.dtype for k, v in d.items()} == dtypes
+    d32 = two_loop(mem32, jax.tree_util.tree_map(
+        lambda v: v.astype(jnp.float32), g))
+    for k in dtypes:        # bf16 keeps 8 mantissa bits
+        np.testing.assert_allclose(np.asarray(d[k], np.float32),
+                                   np.asarray(d32[k]), rtol=3e-2,
+                                   atol=3e-2)
 
 
 # -------------------------------- per-leaf DP calibration (grad_agg fix)
@@ -235,6 +270,39 @@ def test_protocol_tree_trains_multi_leaf_under_attack():
     assert int(mem.count.max()) > 0              # curvature pairs landed
 
 
+@pytest.mark.parametrize("eps", [0.0, 50.0])
+def test_protocol_tree_step_keeps_param_dtypes(eps):
+    """A bf16/f32 parameter tree (the full-width model zoo) comes back in
+    its own dtypes, so the next step reuses the compiled executable."""
+    m, n = 4, 16
+    X = jax.random.normal(jax.random.PRNGKey(8), (m, n, 3))
+    y = X @ jnp.array([1.0, -1.0, 0.5])
+
+    def grad_fn(t, batch):
+        Xb, yb = batch
+        r = Xb @ t["w"].astype(jnp.float32) + t["b"] - yb
+        return 0.5 * jnp.mean(r ** 2), {
+            "w": (Xb.T @ r / n).astype(t["w"].dtype),
+            "b": jnp.mean(r, keepdims=True)}
+
+    cfg = TreeProtocolConfig(hist=2, lr=0.5, eps=eps)
+    theta = {"w": jnp.zeros(3, jnp.bfloat16), "b": jnp.zeros(1)}
+    traces = []
+
+    def step(key, t, mem):
+        traces.append(1)
+        return protocol_tree_rounds(key, t, (X, y), grad_fn, cfg, mem=mem,
+                                    n=n)
+    step = jax.jit(step)
+    mem = bfgs.LBFGSMemory.init_like(cfg.hist, theta, machines=m)
+    for i in range(2):
+        out = step(jax.random.PRNGKey(i), theta, mem)
+        theta, mem = out.theta_qn, out.mem
+    assert {k: v.dtype for k, v in theta.items()} == {
+        "w": jnp.bfloat16, "b": jnp.float32}
+    assert len(traces) == 1
+
+
 # -------------------------------------------- golden byte parity (smoke)
 
 @pytest.mark.slow
@@ -287,6 +355,30 @@ def test_train_launcher_exposes_registry_aggregators():
         ap.parse_args(["--agg", "typo"])
     assert ap.parse_args(["--optimizer", "qn"]).optimizer == "qn"
     assert ap.parse_args(["--config", "glm4-9b"]).arch == "glm4-9b"
+
+
+def test_launcher_compile_cache_and_machine_mesh(monkeypatch):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed path in the checkout; machine meshes have
+    Auto axes (jax.make_mesh's default Explicit axes break X[0])."""
+    from jax.sharding import AxisType
+
+    from repro.launch.cli import checkout_root, enable_compile_cache
+    from repro.launch.mesh import make_machine_mesh
+    repo = Path(__file__).resolve().parents[1]
+    assert checkout_root() == repo
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == old   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+    assert make_machine_mesh(1).axis_types == (AxisType.Auto,)
 
 
 # --------------------------------------------- zoo training compile-once

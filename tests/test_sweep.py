@@ -353,12 +353,12 @@ def test_sharded_sweep_matches_single_host():
            "import sys; sys.path.insert(0, 'src')\n")
     code = textwrap.dedent("""
         import jax, numpy as np
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_machine_mesh
         from repro.sweep import Scenario, SweepExecutor
 
         scens = [Scenario(problem="logistic", m=7, n=100, p=4, eps=e,
                           reps=2, noiseless=True) for e in (10.0, 30.0)]
-        mesh = make_mesh((4,), ("machines",))
+        mesh = make_machine_mesh(4)
         sharded = SweepExecutor(mesh=mesh).run(scens)
         single = SweepExecutor().run(scens)
         for s in scens:
@@ -381,9 +381,9 @@ def test_sharded_sweep_rejects_uneven_machines():
            "'--xla_force_host_platform_device_count=4'\n"
            "import sys; sys.path.insert(0, 'src')\n")
     code = textwrap.dedent("""
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_machine_mesh
         from repro.sweep import Scenario, SweepExecutor
-        mesh = make_mesh((4,), ("machines",))
+        mesh = make_machine_mesh(4)
         try:
             SweepExecutor(mesh=mesh).run(
                 [Scenario(m=5, n=50, p=3, reps=1, noiseless=True)])
