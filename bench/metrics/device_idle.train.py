@@ -1,0 +1,3 @@
+"""Share of the training window in which no operation ran on the
+device (profiler trace)."""
+from bench.readers import idle_percent as read  # noqa: F401

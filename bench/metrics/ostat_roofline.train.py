@@ -1,0 +1,4 @@
+"""HBM roofline share of the order-statistics kernel in the quasi-Newton
+steps: bytes of its calls from their shapes, over 819 GB/s, over the
+kernel's device time (profiler trace)."""
+from bench.readers import ostat_roofline as read  # noqa: F401
