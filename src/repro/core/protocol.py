@@ -61,6 +61,8 @@ from repro.core.bfgs import (LBFGSMemory, VOp, lbfgs_gamma,
 from repro.core.losses import MEstimationProblem
 from repro.core.transport import (tree_add, tree_axpy, tree_sub,
                                   wire_aggregate, wire_corrupt, wire_noise)
+from repro.spans import (ALG1_CENTER, ALG1_LOCAL, ALG1_WIRE,
+                         PROTOCOL_DISPATCH, PROTOCOL_TRACE, scope, span)
 
 
 def vmap_machines(fn, *machine_args, bcast=()):
@@ -291,7 +293,8 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
     else:
         # center (machine 0) is honest in trusted mode
         byz_mask = jnp.concatenate([jnp.zeros((1,), bool), byz_mask])
-    keys = jax.random.split(key, 16)
+    with scope(ALG1_WIRE):
+        keys = jax.random.split(key, 16)
     if theta0 is None:
         theta0 = jnp.zeros((p,), X.dtype)
 
@@ -303,54 +306,66 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
         # rnd = 0-based transmission index (round-aware attacks ramp on
         # it); omniscient attacks see the full machine axis here, exactly
         # the coordinated-adversary view of the wire.
-        return wire_corrupt(kk, vals, byz_mask, attack=attack,
-                            factor=attack_factor, round_idx=rnd)
+        with scope(ALG1_WIRE):
+            return wire_corrupt(kk, vals, byz_mask, attack=attack,
+                                factor=attack_factor, round_idx=rnd)
 
     def noise(kk, x, s):
-        return wire_noise(kk, x, s, noiseless=cfg.noiseless)
+        with scope(ALG1_WIRE):
+            return wire_noise(kk, x, s, noiseless=cfg.noiseless)
 
-    Xc, yc = X[0], y[0]  # center's own shard
+    # Device time is put down to three name scopes (repro.spans): the
+    # machines' local work (ALG1_LOCAL), the wire's noise and corruption
+    # (ALG1_WIRE) and the center (ALG1_CENTER).
+    with scope(ALG1_CENTER):
+        Xc, yc = X[0], y[0]  # center's own shard
 
     # ---- Round 1: local M-estimators -> theta_cq ----------------------
-    theta_local = machine_map(
-        lambda Xi, yi, t0: local.newton_solve(prob, t0, Xi, yi,
-                                              steps=cfg.newton_steps),
-        X, y, bcast=(theta0,))
-    # lambda_s (Assumption 7.3): fixed constant, or calibrated by EACH
-    # machine from its local Hessian spectrum (local data only => no
-    # extra transmission, no extra privacy cost). The center uses its
-    # own lambda_0 when reconstructing the noise variance.
-    if cfg.lambda_s is None:
-        lam_j = machine_map(lambda Xi, yi, ti: jnp.clip(jnp.linalg.eigvalsh(
-            prob.hessian(ti, Xi, yi))[0], 1e-3, None), X, y, theta_local)
-    else:
-        lam_j = jnp.full((m_plus_1,), cfg.lambda_s, X.dtype)
+    with scope(ALG1_LOCAL):
+        theta_local = machine_map(
+            lambda Xi, yi, t0: local.newton_solve(prob, t0, Xi, yi,
+                                                  steps=cfg.newton_steps),
+            X, y, bcast=(theta0,))
+        # lambda_s (Assumption 7.3): fixed constant, or calibrated by EACH
+        # machine from its local Hessian spectrum (local data only => no
+        # extra transmission, no extra privacy cost). The center uses its
+        # own lambda_0 when reconstructing the noise variance.
+        if cfg.lambda_s is None:
+            lam_j = machine_map(lambda Xi, yi, ti: jnp.clip(
+                jnp.linalg.eigvalsh(prob.hessian(ti, Xi, yi))[0], 1e-3,
+                None), X, y, theta_local)
+        else:
+            lam_j = jnp.full((m_plus_1,), cfg.lambda_s, X.dtype)
     s1_base = sb["R1 theta"]
-    s1_j = s1_base / lam_j                         # per-machine sd
-    s1 = center_aggregate(center, s1_j, "median")  # reported/summary value
+    with scope(ALG1_WIRE):
+        s1_j = s1_base / lam_j                     # per-machine sd
+    with scope(ALG1_CENTER):
+        s1 = center_aggregate(center, s1_j, "median")  # reported value
     theta_dp = noise(keys[0], theta_local, s1_j)   # per-machine (m+1,) sd
     theta_dp = corrupt(theta_dp, keys[1], 0)
     sig.append(s1)
 
-    theta_med = center_aggregate(center, theta_dp, "median")
-    if cfg.center_trust == "trusted":
-        sig2 = local.sandwich_diag_variance(prob, theta_med, Xc, yc)
-    else:
-        # untrusted center: median aggregation, no variance needed here
-        sig2 = jnp.ones((p,), X.dtype)
-    s1_eff = 0.0 if cfg.noiseless else s1_j[0]     # center's estimate
-    scale1 = jnp.sqrt((sig2 + n * s1_eff ** 2)) / jnp.sqrt(n)
-    agg1 = "median" if cfg.center_trust == "untrusted" else cfg.aggregator
-    theta_cq = center_aggregate(center, theta_dp, agg1, scale=scale1,
-                                K=cfg.K, trim_beta=cfg.trim_beta)
+    with scope(ALG1_CENTER):
+        theta_med = center_aggregate(center, theta_dp, "median")
+        if cfg.center_trust == "trusted":
+            sig2 = local.sandwich_diag_variance(prob, theta_med, Xc, yc)
+        else:
+            # untrusted center: median aggregation, no variance needed here
+            sig2 = jnp.ones((p,), X.dtype)
+        s1_eff = 0.0 if cfg.noiseless else s1_j[0]     # center's estimate
+        scale1 = jnp.sqrt((sig2 + n * s1_eff ** 2)) / jnp.sqrt(n)
+        agg1 = "median" if cfg.center_trust == "untrusted" else cfg.aggregator
+        theta_cq = center_aggregate(center, theta_dp, agg1, scale=scale1,
+                                    K=cfg.K, trim_beta=cfg.trim_beta)
     if theta_cq_override is not None:
         # warm start / ablation hook: continue the protocol from a
         # caller-supplied initial estimate.
         theta_cq = theta_cq_override
 
     # ---- Round 2: gradients at theta_cq -> g_cq -----------------------
-    grads = machine_map(lambda Xi, yi, t: prob.grad(t, Xi, yi),
-                        X, y, bcast=(theta_cq,))
+    with scope(ALG1_LOCAL):
+        grads = machine_map(lambda Xi, yi, t: prob.grad(t, Xi, yi),
+                            X, y, bcast=(theta_cq,))
     s2 = sb["R2 grad"]
     grads_dp = noise(keys[2], grads, s2)
     grads_dp = corrupt(grads_dp, keys[3], 1)
@@ -358,97 +373,116 @@ def protocol_rounds(key: jax.Array, X: jnp.ndarray, y: jnp.ndarray,
 
     s2_eff = 0.0 if cfg.noiseless else s2
     if cfg.center_trust == "trusted":
-        gvar = local.grad_coordinate_variance(prob, theta_cq, Xc, yc)
+        with scope(ALG1_CENTER):
+            gvar = local.grad_coordinate_variance(prob, theta_cq, Xc, yc)
     else:
         # §4.3: node machines transmit DP variances; center medians them.
         s6 = sb["R2b var"]
         # node machines only (m of m+1 rows): stays a plain vmap — the
         # slice does not divide a machine mesh evenly.
-        node_gvar = jax.vmap(
-            lambda Xi, yi: prob.grad_variance(theta_cq, Xi, yi))(X[1:], y[1:])
+        with scope(ALG1_LOCAL):
+            node_gvar = jax.vmap(lambda Xi, yi: prob.grad_variance(
+                theta_cq, Xi, yi))(X[1:], y[1:])
         node_gvar = noise(keys[4], node_gvar, s6)
-        node_gvar = wire_corrupt(keys[5], node_gvar, byz_mask[1:],
-                                 attack=attack, factor=attack_factor,
-                                 round_idx=1)
-        gvar = center_aggregate(center, node_gvar, "median")
+        with scope(ALG1_WIRE):
+            node_gvar = wire_corrupt(keys[5], node_gvar, byz_mask[1:],
+                                     attack=attack, factor=attack_factor,
+                                     round_idx=1)
+        with scope(ALG1_CENTER):
+            gvar = center_aggregate(center, node_gvar, "median")
         sig.append(s6)
-    scale2 = jnp.sqrt(jnp.maximum(gvar, 1e-12) + n * s2_eff ** 2) / jnp.sqrt(n)
-    g_cq = _agg_for(cfg, "grad", grads_dp, scale2, center)
+    with scope(ALG1_CENTER):
+        scale2 = jnp.sqrt(jnp.maximum(gvar, 1e-12)
+                          + n * s2_eff ** 2) / jnp.sqrt(n)
+        g_cq = _agg_for(cfg, "grad", grads_dp, scale2, center)
 
     # ---- Round 3: Newton directions -> theta_os -----------------------
     def newton_dir(Xi, yi, t_cq, g):
         h = prob.hessian(t_cq, Xi, yi) + 1e-9 * jnp.eye(p, dtype=X.dtype)
         return jnp.linalg.solve(h, g)
-    dirs = machine_map(newton_dir, X, y, bcast=(theta_cq, g_cq))
-    dir_norm = jnp.linalg.norm(dirs, axis=1)          # per machine (Thm 4.5(3))
+    with scope(ALG1_LOCAL):
+        dirs = machine_map(newton_dir, X, y, bcast=(theta_cq, g_cq))
     s3 = sb["R3 newton-dir"]
-    s3_j = (s3 / lam_j) * dir_norm                     # per-machine sd
+    with scope(ALG1_WIRE):
+        dir_norm = jnp.linalg.norm(dirs, axis=1)      # per machine (Thm 4.5(3))
+        s3_j = (s3 / lam_j) * dir_norm                 # per-machine sd
     dirs_dp = noise(keys[6], dirs, s3_j)           # per-machine (m+1,) sd
     dirs_dp = corrupt(dirs_dp, keys[7], 2)
     sig.append(s3)
 
-    if cfg.center_trust == "trusted":
-        hvar = local.newton_dir_variance(prob, theta_cq, Xc, yc, g_cq)
-    else:
-        hvar = median_deviation_variance(dirs_dp, n)
-    s3_0 = (s3 / lam_j[0]) * jnp.linalg.norm(dirs[0])
-    scale3 = jnp.sqrt(jnp.maximum(hvar, 1e-12) + n * s3_0 ** 2) / jnp.sqrt(n)
-    H1 = _agg_for(cfg, "dir", dirs_dp, scale3, center)
-    theta_os = theta_cq - H1
+    with scope(ALG1_CENTER):
+        if cfg.center_trust == "trusted":
+            hvar = local.newton_dir_variance(prob, theta_cq, Xc, yc, g_cq)
+        else:
+            hvar = median_deviation_variance(dirs_dp, n)
+        s3_0 = (s3 / lam_j[0]) * jnp.linalg.norm(dirs[0])
+        scale3 = jnp.sqrt(jnp.maximum(hvar, 1e-12)
+                          + n * s3_0 ** 2) / jnp.sqrt(n)
+        H1 = _agg_for(cfg, "dir", dirs_dp, scale3, center)
+        theta_os = theta_cq - H1
 
     # ---- Round 4: gradient differences -> gdiff_cq, g_os --------------
-    gdiff = machine_map(lambda Xi, yi, t_os, t_cq: prob.grad(t_os, Xi, yi)
-                        - prob.grad(t_cq, Xi, yi),
-                        X, y, bcast=(theta_os, theta_cq))
-    step = theta_os - theta_cq
+    with scope(ALG1_LOCAL):
+        gdiff = machine_map(lambda Xi, yi, t_os, t_cq: prob.grad(t_os, Xi, yi)
+                            - prob.grad(t_cq, Xi, yi),
+                            X, y, bcast=(theta_os, theta_cq))
+    with scope(ALG1_CENTER):
+        step = theta_os - theta_cq
     s4 = sb["R4 grad-diff"]
-    s4_eff = s4 * jnp.linalg.norm(step)
+    with scope(ALG1_WIRE):
+        s4_eff = s4 * jnp.linalg.norm(step)
     gdiff_dp = noise(keys[8], gdiff, s4_eff)
     gdiff_dp = corrupt(gdiff_dp, keys[9], 3)
     sig.append(s4)
 
-    if cfg.center_trust == "trusted":
-        gd = prob.per_sample_grads(theta_os, Xc, yc) \
-            - prob.per_sample_grads(theta_cq, Xc, yc)
-        gdvar = jnp.var(gd, axis=0)
-        gosvar = local.grad_coordinate_variance(prob, theta_os, Xc, yc)
-    else:
-        gdvar = median_deviation_variance(gdiff_dp, n)
-        gosvar = gvar
-    scale4 = jnp.sqrt(jnp.maximum(gdvar, 1e-12)
-                      + n * s4_eff ** 2) / jnp.sqrt(n)
-    gdiff_cq = _agg_for(cfg, "gdiff", gdiff_dp, scale4, center)
-    scale4b = jnp.sqrt(jnp.maximum(gosvar, 1e-12) + n * s2_eff ** 2
-                       + n * s4_eff ** 2) / jnp.sqrt(n)
-    g_os = _agg_for(cfg, "g_os", grads_dp + gdiff_dp, scale4b,
-                    center)
+    with scope(ALG1_CENTER):
+        if cfg.center_trust == "trusted":
+            gd = prob.per_sample_grads(theta_os, Xc, yc) \
+                - prob.per_sample_grads(theta_cq, Xc, yc)
+            gdvar = jnp.var(gd, axis=0)
+            gosvar = local.grad_coordinate_variance(prob, theta_os, Xc, yc)
+        else:
+            gdvar = median_deviation_variance(gdiff_dp, n)
+            gosvar = gvar
+        scale4 = jnp.sqrt(jnp.maximum(gdvar, 1e-12)
+                          + n * s4_eff ** 2) / jnp.sqrt(n)
+        gdiff_cq = _agg_for(cfg, "gdiff", gdiff_dp, scale4, center)
+        scale4b = jnp.sqrt(jnp.maximum(gosvar, 1e-12) + n * s2_eff ** 2
+                           + n * s4_eff ** 2) / jnp.sqrt(n)
+        g_os = _agg_for(cfg, "g_os", grads_dp + gdiff_dp, scale4b,
+                        center)
 
     # ---- Round 5: BFGS directions -> theta_qn --------------------------
-    v = make_v(s=step, y=gdiff_cq)
+    with scope(ALG1_CENTER):
+        v = make_v(s=step, y=gdiff_cq)
 
     def bfgs_dir(Xi, yi, t_cq, vs, vy, vrho, g):
         vop = VOp(s=vs, y=vy, rho=vrho)
         h = prob.hessian(t_cq, Xi, yi) + 1e-9 * jnp.eye(p, dtype=X.dtype)
         hinv_vg = jnp.linalg.solve(h, vop(g, transpose=False))
         return vop(hinv_vg, transpose=True)            # (4.15) machine part
-    h3 = machine_map(bfgs_dir, X, y,
-                     bcast=(theta_cq, v.s, v.y, v.rho, g_os))
+    with scope(ALG1_LOCAL):
+        h3 = machine_map(bfgs_dir, X, y,
+                         bcast=(theta_cq, v.s, v.y, v.rho, g_os))
     s5 = sb["R5 bfgs-dir"]
-    s5_j = s5 * jnp.linalg.norm(h3, axis=1)
+    with scope(ALG1_WIRE):
+        s5_j = s5 * jnp.linalg.norm(h3, axis=1)
     h3_dp = noise(keys[10], h3, s5_j)              # per-machine (m+1,) sd
     h3_dp = corrupt(h3_dp, keys[11], 4)
     sig.append(s5)
 
-    if cfg.center_trust == "trusted":
-        h3var = local.bfgs_dir_variance(prob, theta_cq, Xc, yc, v, g_os)
-    else:
-        h3var = median_deviation_variance(h3_dp, n)
-    s5_0 = s5 * jnp.linalg.norm(h3[0])
-    scale5 = jnp.sqrt(jnp.maximum(h3var, 1e-12) + n * s5_0 ** 2) / jnp.sqrt(n)
-    h3_agg = _agg_for(cfg, "h3", h3_dp, scale5, center)
-    # center-side rank-1 term: rho (s s^T) g_os  (below eq. 4.15)
-    H2 = h3_agg + v.rho * step * jnp.dot(step, g_os)
-    theta_qn = theta_os - H2
+    with scope(ALG1_CENTER):
+        if cfg.center_trust == "trusted":
+            h3var = local.bfgs_dir_variance(prob, theta_cq, Xc, yc, v, g_os)
+        else:
+            h3var = median_deviation_variance(h3_dp, n)
+        s5_0 = s5 * jnp.linalg.norm(h3[0])
+        scale5 = jnp.sqrt(jnp.maximum(h3var, 1e-12)
+                          + n * s5_0 ** 2) / jnp.sqrt(n)
+        h3_agg = _agg_for(cfg, "h3", h3_dp, scale5, center)
+        # center-side rank-1 term: rho (s s^T) g_os  (below eq. 4.15)
+        H2 = h3_agg + v.rho * step * jnp.dot(step, g_os)
+        theta_qn = theta_os - H2
 
     k = n_transmissions(cfg)
     assert len(sig) == k, "spend ledger out of sync with transmission_names"
@@ -621,7 +655,10 @@ class DPQNProtocol:
     over replicate keys. ``jit=False`` keeps the eager per-op path (used as
     the baseline in benchmarks/bench_protocol.py). ``trace_count`` counts
     how many times the core was (re)traced — tests assert a second call
-    with identical shapes does not retrace.
+    with identical shapes does not retrace. Each engine call opens the
+    host span ``repro.protocol.dispatch`` (trace, lowering, cache fetch,
+    launch), and each trace ``repro.protocol.trace`` inside it
+    (repro.spans).
     """
 
     def __init__(self, problem: MEstimationProblem, cfg: ProtocolConfig,
@@ -644,11 +681,13 @@ class DPQNProtocol:
             def rounds(key, X, y, byz_mask, theta0, theta_cq_override,
                        attack_factor):
                 self.trace_count += 1
-                return protocol_rounds(
-                    key, X, y, self.problem, self.cfg, byz_mask=byz_mask,
-                    attack=attack, attack_factor=attack_factor,
-                    theta0=theta0, theta_cq_override=theta_cq_override,
-                    machine_map=self._mmap, center=self._center)
+                with span(PROTOCOL_TRACE):
+                    return protocol_rounds(
+                        key, X, y, self.problem, self.cfg,
+                        byz_mask=byz_mask, attack=attack,
+                        attack_factor=attack_factor, theta0=theta0,
+                        theta_cq_override=theta_cq_override,
+                        machine_map=self._mmap, center=self._center)
             batched = jax.vmap(rounds, in_axes=(0,) + (None,) * 6)
             if self._jit:
                 rounds, batched = jax.jit(rounds), jax.jit(batched)
@@ -681,8 +720,9 @@ class DPQNProtocol:
             theta0: Optional[jnp.ndarray] = None,
             theta_cq_override: Optional[jnp.ndarray] = None) -> ProtocolResult:
         single, _ = self._engine(attack)
-        arrays = single(key, X, y, byz_mask, theta0, theta_cq_override,
-                        attack_factor)
+        with span(PROTOCOL_DISPATCH):
+            arrays = single(key, X, y, byz_mask, theta0, theta_cq_override,
+                            attack_factor)
         return self._finalize(arrays)
 
     # -- batched Monte-Carlo driver ----------------------------------------
@@ -699,5 +739,6 @@ class DPQNProtocol:
         (e.g. ``theta_qn``: (reps, p)). Data/masks are shared across
         replicates; only the PRNG key varies."""
         _, batched = self._engine(attack)
-        return batched(keys, X, y, byz_mask, theta0, theta_cq_override,
-                       attack_factor)
+        with span(PROTOCOL_DISPATCH):
+            return batched(keys, X, y, byz_mask, theta0, theta_cq_override,
+                           attack_factor)

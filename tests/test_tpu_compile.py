@@ -124,3 +124,57 @@ def test_sharded_qn_train_step_compiles_for_v5e_2x2(topo, tpu_branch):
              for k in ("tokens", "labels")}
     lowered = trainer.step_fn.lower(params, mem, batch, key, None)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def _innermost_alg1_scope(op_name):
+    """The innermost of Algorithm 1's name scopes on an op's path (under
+    vmap a scope reads ``vmap(alg1.center)``), or None."""
+    import re
+    from repro import spans
+    found = re.findall(r"(?:^|[/(])(%s)(?=[/)]|$)" % "|".join(
+        re.escape(s) for s in spans.ALG1_SCOPES), op_name)
+    return found[-1] if found else None
+
+
+@pytest.mark.parametrize("placement", ["one_chip", "mesh_2x2"])
+def test_engine_scopes_survive_the_tpu_compiler(topo, tpu_branch,
+                                                placement):
+    """Algorithm 1's engine compiled for v5e keeps its name scopes in the
+    op metadata (the profiler reads device time per scope from it), on
+    one chip as ``run_monte_carlo`` runs it and sharded over a 2x2
+    machine mesh; every order-statistics kernel lies under the center."""
+    import re
+    from repro import spans
+    from repro.configs.base import ProtocolConfig
+    from repro.core import DPQNProtocol, get_problem
+    from repro.dist.sharded_protocol import center_map, machine_map
+
+    prob, cfg = get_problem("logistic"), ProtocolConfig(eps=10.0, delta=0.05)
+    if placement == "one_chip":
+        place = SingleDeviceSharding(topo.devices[0])
+        rows = repl = place
+        _, engine = DPQNProtocol(prob, cfg)._engine("scale")
+        keys = jax.ShapeDtypeStruct((100, 2), jnp.uint32, sharding=repl)
+        m = 51
+    else:
+        mesh = Mesh(topo.devices, ("machines",),
+                    axis_types=(AxisType.Auto,))
+        rows = NamedSharding(mesh, PartitionSpec("machines"))
+        repl = NamedSharding(mesh, PartitionSpec())
+        engine, _ = DPQNProtocol(prob, cfg, machine_map=machine_map(mesh),
+                                 center=center_map(mesh))._engine("scale")
+        keys = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+        m = 52
+    X = jax.ShapeDtypeStruct((m, 1000, 10), jnp.float32, sharding=rows)
+    y = jax.ShapeDtypeStruct((m, 1000), jnp.float32, sharding=rows)
+    byz = jax.ShapeDtypeStruct((m - 1,), jnp.bool_, sharding=repl)
+    text = engine.lower(keys, X, y, byz, None, None, -3.0).compile().as_text()
+    scopes = {_innermost_alg1_scope(n)
+              for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert set(spans.ALG1_SCOPES) <= scopes
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    for ln in kernels:
+        op = re.search(r'op_name="([^"]*)"', ln)
+        assert op and _innermost_alg1_scope(op.group(1)) == spans.ALG1_CENTER
