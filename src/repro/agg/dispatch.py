@@ -185,11 +185,21 @@ class DispatchTable:
 _CACHE: dict = {}
 #: test/in-process injection: platform -> DispatchTable
 _INJECTED: dict = {}
+_generation = 0
 
 
 def clear_cache() -> None:
     """Drop loaded tables (picks up a changed ENV_VAR / table file)."""
+    global _generation
     _CACHE.clear()
+    _generation += 1
+
+
+def generation() -> int:
+    """Changes with every :func:`clear_cache`, and so with every
+    :func:`set_table`: a cache of what was traced under the tables
+    (``repro.api``'s engines) keys its entries by it."""
+    return _generation
 
 
 def set_table(table: Optional[DispatchTable],

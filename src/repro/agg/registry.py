@@ -62,14 +62,23 @@ class Aggregator:
 
 
 _REGISTRY: Dict[str, Aggregator] = {}
+_generation = 0
 
 
 def register(agg: Aggregator) -> Aggregator:
     """Register (or replace) an aggregator under ``agg.name``."""
+    global _generation
     if agg.batching not in ("grid", "vmap"):
         raise ValueError(f"unknown batching rule {agg.batching!r}")
     _REGISTRY[agg.name] = agg
+    _generation += 1
     return agg
+
+
+def generation() -> int:
+    """Changes with every :func:`register`: a cache of what was traced
+    from the registry (``repro.api``'s engines) keys its entries by it."""
+    return _generation
 
 
 def get_aggregator(name: str) -> Aggregator:

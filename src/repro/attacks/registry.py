@@ -59,6 +59,7 @@ class Attack:
 
 
 _REGISTRY: Dict[str, Attack] = {}
+_generation = 0
 
 #: launcher-friendly aliases (the historical dist/grad_agg names)
 ALIASES: Dict[str, str] = {"sign": "signflip", "noise": "gauss"}
@@ -66,17 +67,28 @@ ALIASES: Dict[str, str] = {"sign": "signflip", "noise": "gauss"}
 
 def register(attack: Attack) -> Attack:
     """Register (or replace) an attack under ``attack.name``."""
+    global _generation
     if attack.name in ALIASES:
         raise ValueError(f"{attack.name!r} shadows alias for "
                          f"{ALIASES[attack.name]!r}")
     _REGISTRY[attack.name] = attack
+    _generation += 1
     return attack
 
 
 def unregister(name: str) -> None:
     """Remove a registered attack (tests registering temporary entries
     clean up through this instead of the private dict)."""
+    global _generation
     _REGISTRY.pop(name, None)
+    _generation += 1
+
+
+def generation() -> int:
+    """Changes with every :func:`register` and :func:`unregister`: a
+    cache of what was traced from the registry (``repro.api``'s engines)
+    keys its entries by it."""
+    return _generation
 
 
 def resolve(name: str) -> str:

@@ -62,7 +62,8 @@ from repro.core.losses import MEstimationProblem
 from repro.core.transport import (tree_add, tree_axpy, tree_sub,
                                   wire_aggregate, wire_corrupt, wire_noise)
 from repro.spans import (ALG1_CENTER, ALG1_LOCAL, ALG1_WIRE,
-                         PROTOCOL_DISPATCH, PROTOCOL_TRACE, scope, span)
+                         PROTOCOL_BUILD, PROTOCOL_DISPATCH, PROTOCOL_TRACE,
+                         scope, span)
 
 
 def vmap_machines(fn, *machine_args, bcast=()):
@@ -655,8 +656,9 @@ class DPQNProtocol:
     over replicate keys. ``jit=False`` keeps the eager per-op path (used as
     the baseline in benchmarks/bench_protocol.py). ``trace_count`` counts
     how many times the core was (re)traced — tests assert a second call
-    with identical shapes does not retrace. Each engine call opens the
-    host span ``repro.protocol.dispatch`` (trace, lowering, cache fetch,
+    with identical shapes does not retrace. Building an attack mode's
+    engines opens the host span ``repro.protocol.build``, each engine
+    call ``repro.protocol.dispatch`` (trace, lowering, cache fetch,
     launch), and each trace ``repro.protocol.trace`` inside it
     (repro.spans).
     """
@@ -678,20 +680,21 @@ class DPQNProtocol:
         """(single, batched-over-keys) callables for one attack mode; built
         lazily and cached so jit compiles once per protocol instance."""
         if attack not in self._engines:
-            def rounds(key, X, y, byz_mask, theta0, theta_cq_override,
-                       attack_factor):
-                self.trace_count += 1
-                with span(PROTOCOL_TRACE):
-                    return protocol_rounds(
-                        key, X, y, self.problem, self.cfg,
-                        byz_mask=byz_mask, attack=attack,
-                        attack_factor=attack_factor, theta0=theta0,
-                        theta_cq_override=theta_cq_override,
-                        machine_map=self._mmap, center=self._center)
-            batched = jax.vmap(rounds, in_axes=(0,) + (None,) * 6)
-            if self._jit:
-                rounds, batched = jax.jit(rounds), jax.jit(batched)
-            self._engines[attack] = (rounds, batched)
+            with span(PROTOCOL_BUILD):
+                def rounds(key, X, y, byz_mask, theta0, theta_cq_override,
+                           attack_factor):
+                    self.trace_count += 1
+                    with span(PROTOCOL_TRACE):
+                        return protocol_rounds(
+                            key, X, y, self.problem, self.cfg,
+                            byz_mask=byz_mask, attack=attack,
+                            attack_factor=attack_factor, theta0=theta0,
+                            theta_cq_override=theta_cq_override,
+                            machine_map=self._mmap, center=self._center)
+                batched = jax.vmap(rounds, in_axes=(0,) + (None,) * 6)
+                if self._jit:
+                    rounds, batched = jax.jit(rounds), jax.jit(batched)
+                self._engines[attack] = (rounds, batched)
         return self._engines[attack]
 
     def _finalize(self, arrays: ProtocolArrays) -> ProtocolResult:

@@ -63,12 +63,21 @@ class Accountant:
 
 
 _REGISTRY: Dict[str, Accountant] = {}
+_generation = 0
 
 
 def register(acct: Accountant) -> Accountant:
     """Register (or replace) an accountant under ``acct.name``."""
+    global _generation
     _REGISTRY[acct.name] = acct
+    _generation += 1
     return acct
+
+
+def generation() -> int:
+    """Changes with every :func:`register`: a cache of what was traced
+    from the registry (``repro.api``'s engines) keys its entries by it."""
+    return _generation
 
 
 def get_accountant(name: str) -> Accountant:

@@ -23,6 +23,9 @@ import jax
 PROTOCOL_DISPATCH = "repro.protocol.dispatch"
 #: one trace of ``protocol_rounds``; opened only while JAX traces
 PROTOCOL_TRACE = "repro.protocol.trace"
+#: ``DPQNProtocol`` building the jitted engines of one attack mode; opened
+#: only when it has none for that mode yet
+PROTOCOL_BUILD = "repro.protocol.build"
 
 #: Algorithm 1's machines' local work: Newton solves, gradients, Hessian
 #: solves, gradient differences

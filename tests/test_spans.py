@@ -73,6 +73,34 @@ def test_first_call_traces_inside_dispatch_second_does_not(tmp_path,
     assert proto.trace_count == 1
 
 
+def test_facade_builds_the_engine_once_before_its_first_dispatch(tmp_path,
+                                                                 shards):
+    from repro import api
+    X, y = shards
+    keys = jax.random.split(jax.random.PRNGKey(1), REPS)
+    api._protocols.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for call in ("test.first", "test.second"):
+            with jax.profiler.TraceAnnotation(call):
+                jax.block_until_ready(
+                    api.run_monte_carlo(X, y, cfg=_cfg(), keys=keys))
+    finally:
+        jax.profiler.stop_trace()
+        api._protocols.clear()
+    ev = _host_events(tmp_path)
+    first = [e for e in ev if e[0] == "test.first"][0]
+    second = [e for e in ev if e[0] == "test.second"][0]
+
+    builds = [e for e in ev if e[0] == spans.PROTOCOL_BUILD]
+    assert _inside(ev, first, spans.PROTOCOL_BUILD) == builds
+    assert len(builds) == 1
+    (dispatch,) = _inside(ev, first, spans.PROTOCOL_DISPATCH)
+    assert builds[0][2] <= dispatch[1]
+    assert len(_inside(ev, second, spans.PROTOCOL_DISPATCH)) == 1
+    assert not _inside(ev, second, spans.PROTOCOL_TRACE)
+
+
 def _op_names(hlo_text):
     return re.findall(r'op_name="([^"]*)"', hlo_text)
 
